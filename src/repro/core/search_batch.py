@@ -191,8 +191,10 @@ def greedy_upper_batch(graph: HnswGraph, Q: jax.Array, metric: str):
         nbr_pos = upper[pos]                               # [B, M_U]
         valid = nbr_pos >= 0
         nbr_ids = jnp.where(valid, upper_ids[jnp.maximum(nbr_pos, 0)], -1)
-        nd = batch_gather_dist(Q, vectors,
-                               jnp.where(act[:, None], nbr_ids, -1), metric)
+        with jax.named_scope("navix.gather"):
+            nd = batch_gather_dist(Q, vectors,
+                                   jnp.where(act[:, None], nbr_ids, -1),
+                                   metric)
         jj = jnp.argmin(nd, axis=1)
         best = jnp.take_along_axis(nd, jj[:, None], axis=1)[:, 0]
         upd = act & (best < d)
@@ -289,41 +291,52 @@ def _loop_fns(graph: HnswGraph, Q: jax.Array, sel2: jax.Array,
 
     efs_eff = efs if efs_lanes is None else efs_lanes
 
+    # the named scopes (navix.frontier / visited / semimask / gather /
+    # merge) tag the step's ops for a device profile; they change op
+    # metadata only, never the compiled program. lane_cond stays
+    # unscoped: it is also the loop's condition, where a scope renames
+    # instructions (some names derive from the name stack)
     def lane_cond(st: _BatchState):
         _, d_min = _frontier_min(st)
         keep = (d_min < jnp.inf) & (d_min <= _r_max(st, efs_eff))
         return keep & (st.it < max_iters)
 
     def body(st: _BatchState) -> _BatchState:
-        live = lane_cond(st)                               # [B]
-        j, _ = _frontier_min(st)
-        c_min = jnp.take_along_axis(st.ids, j[:, None], axis=1)[:, 0]
-        # retired lanes contribute no candidates to the shared gathers
-        nbrs = jnp.where(live[:, None],
-                         lower[jnp.maximum(c_min, 0)], -1)  # [B, M_L]
+        with jax.named_scope("navix.frontier"):
+            live = lane_cond(st)                           # [B]
+            j, _ = _frontier_min(st)
+            c_min = jnp.take_along_axis(st.ids, j[:, None], axis=1)[:, 0]
+            # retired lanes contribute no candidates to the shared gathers
+            nbrs = jnp.where(live[:, None],
+                             lower[jnp.maximum(c_min, 0)], -1)  # [B, M_L]
 
-        if mode == int(Heuristic.ADAPTIVE_LOCAL):
-            deg = (nbrs >= 0).sum(axis=1)
-            # each lane estimates sigma_l against its OWN selected set
-            sigma_l = bitset.count_members_batch(sel2, nbrs) / \
-                jnp.maximum(deg, 1)
-            branch = adaptive_rule(sigma_l, m_l, params.ub, params.lf)
-        else:
-            branch = global_branch
-        is_dir = branch == int(Heuristic.DIRECTED)
+            if mode == int(Heuristic.ADAPTIVE_LOCAL):
+                deg = (nbrs >= 0).sum(axis=1)
+                # each lane estimates sigma_l against its OWN selected set
+                sigma_l = bitset.count_members_batch(sel2, nbrs) / \
+                    jnp.maximum(deg, 1)
+                branch = adaptive_rule(sigma_l, m_l, params.ub, params.lf)
+            else:
+                branch = global_branch
+            is_dir = branch == int(Heuristic.DIRECTED)
 
         # shared first-degree pass: one gather serves every branch
-        visited_t = bitset.test_batch(st.visited, nbrs)            # [B, M]
+        with jax.named_scope("navix.visited"):
+            visited_t = bitset.test_batch(st.visited, nbrs)        # [B, M]
         new1 = (nbrs >= 0) & ~visited_t
-        sel1 = bitset.test_batch(sel2, nbrs) & ~visited_t  # == cand1 mask
+        with jax.named_scope("navix.semimask"):
+            sel1 = bitset.test_batch(sel2, nbrs) & ~visited_t  # == cand1
         cand1 = jnp.where(sel1, nbrs, -1)
-        d_all = batch_gather_dist(Q, vectors, nbrs, metric)
+        with jax.named_scope("navix.gather"):
+            d_all = batch_gather_dist(Q, vectors, nbrs, metric)
         d1 = jnp.where(sel1, d_all, jnp.inf)
         n1 = sel1.sum(axis=1)
         # directed marks every neighbor it ordered; the others only the
         # selected candidates they actually inserted
         mark1 = jnp.where(is_dir[:, None], new1, sel1)
-        visited1 = bitset.set_bits_batch(st.visited, jnp.where(mark1, nbrs, -1))
+        with jax.named_scope("navix.visited"):
+            visited1 = bitset.set_bits_batch(st.visited,
+                                             jnp.where(mark1, nbrs, -1))
 
         # second-degree parents: distance-ordered for directed, scan order
         # for blind, none for onehop-s / retired lanes
@@ -339,15 +352,20 @@ def _loop_fns(graph: HnswGraph, Q: jax.Array, sel2: jax.Array,
             nb2 = lower[jnp.maximum(parents, 0)]           # [B, M, M]
             flat = jnp.where((parents >= 0)[:, :, None], nb2,
                              -1).reshape(bsz, -1)
-            elig = ((flat >= 0) & bitset.test_batch(sel2, flat)
-                    & ~bitset.test_batch(visited1, flat))
+            elig = flat >= 0
+            with jax.named_scope("navix.semimask"):
+                elig = elig & bitset.test_batch(sel2, flat)
+            with jax.named_scope("navix.visited"):
+                elig = elig & ~bitset.test_batch(visited1, flat)
             cand = _take_first_batch(elig, flat, 2 * k2)   # over-take ...
             cand = dedupe(cand)                            # ... dedupe ...
             cand2 = _take_first_batch(cand >= 0, cand, k2,
                                       budget=budget)       # ... then cap
-            d2 = batch_gather_dist(Q, vectors, cand2, metric)
-            return (cand2, d2, bitset.set_bits_batch(visited1, cand2),
-                    (cand2 >= 0).sum(axis=1))
+            with jax.named_scope("navix.gather"):
+                d2 = batch_gather_dist(Q, vectors, cand2, metric)
+            with jax.named_scope("navix.visited"):
+                visited2 = bitset.set_bits_batch(visited1, cand2)
+            return cand2, d2, visited2, (cand2 >= 0).sum(axis=1)
 
         def skip_second(args):
             visited1, _, _ = args
@@ -366,37 +384,39 @@ def _loop_fns(graph: HnswGraph, Q: jax.Array, sel2: jax.Array,
         # retire the expanded slot and merge candidates (per lane);
         # one-hot mask arithmetic instead of batched .at[] scatters --
         # XLA CPU serializes per-lane scatters, these are the hot path
-        slot = jnp.arange(efs)[None, :] == j[:, None]      # [B, efs]
-        exp = st.exp | slot
-        sel_j = jnp.take_along_axis(st.sel, j[:, None], axis=1)
-        d = jnp.where(slot & ~sel_j, jnp.inf, st.d)
+        with jax.named_scope("navix.merge"):
+            slot = jnp.arange(efs)[None, :] == j[:, None]  # [B, efs]
+            exp = st.exp | slot
+            sel_j = jnp.take_along_axis(st.sel, j[:, None], axis=1)
+            d = jnp.where(slot & ~sel_j, jnp.inf, st.d)
 
-        cand_ids = jnp.concatenate([cand1, cand2], axis=1)
-        cand_d = jnp.concatenate([d1, d2], axis=1)
-        all_d = jnp.concatenate(
-            [d, jnp.where(cand_ids >= 0, cand_d, jnp.inf)], axis=1)
-        all_id = jnp.concatenate([st.ids, cand_ids], axis=1)
-        all_exp = jnp.concatenate(
-            [exp, jnp.zeros_like(cand_ids, dtype=bool)], axis=1)
-        all_sel = jnp.concatenate([st.sel, cand_ids >= 0], axis=1)
+            cand_ids = jnp.concatenate([cand1, cand2], axis=1)
+            cand_d = jnp.concatenate([d1, d2], axis=1)
+            all_d = jnp.concatenate(
+                [d, jnp.where(cand_ids >= 0, cand_d, jnp.inf)], axis=1)
+            all_id = jnp.concatenate([st.ids, cand_ids], axis=1)
+            all_exp = jnp.concatenate(
+                [exp, jnp.zeros_like(cand_ids, dtype=bool)], axis=1)
+            all_sel = jnp.concatenate([st.sel, cand_ids >= 0], axis=1)
 
-        # navilint: op-ok the single fused beam-merge top_k PR 3 kept
-        neg, order2 = lax.top_k(-all_d, efs)
-        new_d = -neg
-        new_id = jnp.take_along_axis(all_id, order2, axis=1)
-        new_exp = jnp.take_along_axis(all_exp, order2, axis=1)
-        new_sel = jnp.take_along_axis(all_sel, order2, axis=1)
-        if efs_lanes is not None:
-            # ragged beam tail: the merge is sorted ascending, so its
-            # first efs_lanes[b] slots equal the top-efs_lanes[b] merge
-            # of an efs_lanes[b]-wide beam; clearing the tail keeps the
-            # induction exact and stops small-efs lanes paying the
-            # full-cap radius (their r_max closes at their own efs)
-            tail = jnp.arange(efs)[None, :] >= efs_lanes[:, None]
-            new_d = jnp.where(tail, jnp.inf, new_d)
-            new_id = jnp.where(tail, -1, new_id)
-            new_exp = new_exp | tail
-            new_sel = new_sel & ~tail
+            # navilint: op-ok the single fused beam-merge top_k PR 3 kept
+            neg, order2 = lax.top_k(-all_d, efs)
+            new_d = -neg
+            new_id = jnp.take_along_axis(all_id, order2, axis=1)
+            new_exp = jnp.take_along_axis(all_exp, order2, axis=1)
+            new_sel = jnp.take_along_axis(all_sel, order2, axis=1)
+            if efs_lanes is not None:
+                # ragged beam tail: the merge is sorted ascending, so its
+                # first efs_lanes[b] slots equal the top-efs_lanes[b]
+                # merge of an efs_lanes[b]-wide beam; clearing the tail
+                # keeps the induction exact and stops small-efs lanes
+                # paying the full-cap radius (their r_max closes at their
+                # own efs)
+                tail = jnp.arange(efs)[None, :] >= efs_lanes[:, None]
+                new_d = jnp.where(tail, jnp.inf, new_d)
+                new_id = jnp.where(tail, -1, new_id)
+                new_exp = new_exp | tail
+                new_sel = new_sel & ~tail
         keep = live[:, None]
         return _BatchState(
             d=jnp.where(keep, new_d, st.d),
@@ -418,12 +438,13 @@ def _extract_results(st: _BatchState, efs: int):
     """Selected-slot top-k of the final beams: (dists[B, efs], ids[B, efs],
     per-lane stats with upper_dc left zero for the caller to fill)."""
     bsz = st.it.shape[0]
-    res_d = jnp.where(st.sel & (st.ids >= 0), st.d, jnp.inf)
-    # navilint: op-ok one top_k per search at extraction, not per step
-    neg, order = lax.top_k(-res_d, efs)
-    out_d = -neg
-    out_id = jnp.where(jnp.isfinite(out_d),
-                       jnp.take_along_axis(st.ids, order, axis=1), -1)
+    with jax.named_scope("navix.merge"):
+        res_d = jnp.where(st.sel & (st.ids >= 0), st.d, jnp.inf)
+        # navilint: op-ok one top_k per search at extraction, not per step
+        neg, order = lax.top_k(-res_d, efs)
+        out_d = -neg
+        out_id = jnp.where(jnp.isfinite(out_d),
+                           jnp.take_along_axis(st.ids, order, axis=1), -1)
     stats = SearchStats(iters=st.it, t_dc=st.t_dc, s_dc=st.s_dc,
                         upper_dc=jnp.zeros((bsz,), jnp.int32),
                         picks=st.picks)

@@ -81,6 +81,11 @@ class Response:
                                   # best-effort answer), "timeout"
                                   # (deadline hit first; ids are all -1,
                                   # NEVER a truncated id list)
+    # the engine's work for this request (LaneBatch.work); None where
+    # no search ran (timeout) or the backend keeps no stats (sharded)
+    iters: Optional[int] = None   # lower-level loop iterations
+    dc: Optional[int] = None      # distance computations (t_dc + upper_dc)
+    picks: Optional[tuple] = None  # times onehop-s / directed / blind ran
 
     @property
     def timeout(self) -> bool:
@@ -217,11 +222,10 @@ class SearchEngine:
         # in lockstep with latencies_ms (service = exec + prefilter share)
         self.queue_waits_ms: list[float] = []
         self.service_ms: list[float] = []
-        # host-vs-device split of every stepped chunk, summed over drains
-        # (see LaneBatch.timing): host_gap = host work the device waited
-        # for; host_overlap = host work hidden behind an in-flight chunk
-        self.chunk_timing = {"n_chunks": 0, "host_gap_ms": 0.0,
-                             "host_overlap_ms": 0.0, "device_wait_ms": 0.0}
+        # LaneBatch.timing()'s counters, summed over drains: host_gap =
+        # host work the device waited for; host_overlap = host work hidden
+        # behind an in-flight chunk; the exact-tier re-rank's time
+        self.chunk_timing: dict = defaultdict(int)
         # LaneBatch reuse across drains, keyed by the fused program shape:
         # building one per drain pays parked-state allocation + mesh
         # placement every time (the dominant per-drain setup cost on
@@ -395,7 +399,8 @@ class SearchEngine:
                 r, parts, t0 = lanes.meta[i]
                 k_r = parts.knn.k
                 rows.append((r, parts, t0, t_done,
-                             ids[i, :k_r], dists[i, :k_r], degraded))
+                             ids[i, :k_r], dists[i, :k_r], degraded,
+                             lanes.work(i)))
                 lanes.release(i)
             done.clear()
             return rows
@@ -403,7 +408,7 @@ class SearchEngine:
         def emit(rows):
             """Build + record the responses for ``collect``'s rows --
             host-only, safe to run while a device chunk is in flight."""
-            for r, parts, t0, t_done, ids_i, dists_i, degraded in rows:
+            for r, parts, t0, t_done, ids_i, dists_i, degraded, work in rows:
                 _, sigma, pf_ms, cnt = sel_info[parts.selection]
                 pf_share = pf_ms / cnt
                 queue_ms = (t0 - r.t_enqueue) * 1e3
@@ -413,7 +418,7 @@ class SearchEngine:
                     rid=r.rid, ids=ids_i, dists=dists_i,
                     queue_ms=queue_ms, exec_ms=exec_ms,
                     prefilter_ms=pf_share, sigma=float(sigma),
-                    degraded=degraded))
+                    degraded=degraded, **work))
 
         while pending or lanes.occupied_count():
             n_running = lanes.occupied_count() - len(done)

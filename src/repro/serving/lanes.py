@@ -41,10 +41,12 @@ from __future__ import annotations
 import time
 from typing import Any, Optional
 
+import jax
 import numpy as np
 
 from repro.core.distributed import ShardedNavix
 from repro.core.navix import NavixIndex
+from repro.serving.spans import note_program, span
 from repro.storage.columnar import GraphStore  # noqa: F401  (re-export site)
 
 
@@ -123,10 +125,27 @@ class _FlatLanes:
                                              self.params, n_steps,
                                              sigma_g=sigj, efs_lanes=efsj)
 
+    def note_steps(self, Qj, selj, st, n_steps, sigj, efsj) -> None:
+        """Record the chunk program for a profile's op -> scope map
+        (``spans.op_scopes``) by abstract shapes only: the record keeps
+        no device buffer alive."""
+        from repro.core import search_batch as sb
+        args = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding),
+            (self.graph, Qj, selj, st, sigj, efsj))
+        graph, Q, sel, state, sig, efs = args
+        params = self.params
+        leaves, tree = jax.tree.flatten(args)
+        note_program((params, n_steps, tree, tuple(leaves)),
+                     lambda: sb.engine_steps_overlap.lower(
+                         graph, Q, sel, state, params, n_steps,
+                         sigma_g=sig, efs_lanes=efs))
+
     def finalize(self, st, udc, alive):
         from repro.core import search_batch as sb
         fin = sb.engine_finalize(st, udc, self.params)
-        return fin.ids, fin.dists
+        return fin.ids, fin.dists, fin.stats
 
     def evict(self, st, udc, evict):
         import jax.numpy as jnp
@@ -234,10 +253,17 @@ class _ShardLanes:
         return self._steps.lower(self.sn.graphs, Qj, selj, st, n_steps,
                                  efs_lanes=efsj)
 
+    def note_steps(self, Qj, selj, st, n_steps, sigj, efsj) -> None:
+        # no op -> scope map for the sharded programs: a profile of a
+        # sharded index reads no engine scope shares
+        pass
+
     def finalize(self, st, udc, alive):
+        # beams only: no per-lane stats (a response's work counters stay
+        # None on a sharded index)
         import jax.numpy as jnp
         d, ids = self._finalize(st, udc, jnp.asarray(alive))
-        return ids, d
+        return ids, d, None
 
     def evict(self, st, udc, evict):
         return self._evict(st, udc, self.place_lanes(np.asarray(evict)))
@@ -284,14 +310,16 @@ class LaneBatch:
         self.selj = self.backend.place_sel(self.selh)
         self.sigj = self.backend.place_lanes(self.sigh)
         self.efsj = self.backend.place_lanes(self.efsh)
+        # the last finalize's per-lane work counters (host SearchStats;
+        # None on a sharded backend)
+        self.stats = None
         # overlapped-stepping bookkeeping (host-vs-device observability)
         self._live_pending = None          # in-flight chunk's live[B]
+        self._noted: set = set()           # n_steps whose program is noted
         self._t_dispatched = 0.0
         self._t_wait_end = time.perf_counter()
-        self.n_chunks = 0
-        self.host_gap_ms = 0.0      # host work NOT overlapped (wait->dispatch)
-        self.host_overlap_ms = 0.0  # host work overlapped (dispatch->wait)
-        self.device_wait_ms = 0.0   # blocked on the device inside step_wait
+        self._timing: dict = {}
+        self.reset_timing()
 
     @property
     def n_shards(self) -> int:
@@ -343,11 +371,13 @@ class LaneBatch:
                              "admission to LaneBatch.free_count()")
         if not used:
             return used
-        (self.Qj, self.selj, self.sigj, self.efsj,
-         refill_j) = self.backend.place_admit(
-            self.Qh, self.selh, self.sigh, self.efsh, refill)
-        self.st, self.udc = self.backend.refill(
-            self.Qj, self.selj, self.st, self.udc, refill_j)
+        with span("navix.admit", lanes=len(used),
+                  semimask_bytes=self.selh.nbytes):
+            (self.Qj, self.selj, self.sigj, self.efsj,
+             refill_j) = self.backend.place_admit(
+                self.Qh, self.selh, self.sigh, self.efsh, refill)
+            self.st, self.udc = self.backend.refill(
+                self.Qj, self.selj, self.st, self.udc, refill_j)
         return used
 
     @property
@@ -365,7 +395,11 @@ class LaneBatch:
             raise RuntimeError("a device chunk is already in flight; "
                                "step_wait() it first")
         t0 = time.perf_counter()
-        self.host_gap_ms += (t0 - self._t_wait_end) * 1e3
+        self._timing["host_gap_ms"] += (t0 - self._t_wait_end) * 1e3
+        if n_steps not in self._noted:
+            self._noted.add(n_steps)
+            self.backend.note_steps(self.Qj, self.selj, self.st, n_steps,
+                                    self.sigj, self.efsj)
         self.st, self._live_pending = self.backend.steps(
             self.Qj, self.selj, self.st, n_steps, self.sigj, self.efsj)
         self._t_dispatched = time.perf_counter()
@@ -377,14 +411,13 @@ class LaneBatch:
             raise RuntimeError("no device chunk in flight; step_async() "
                                "first")
         t1 = time.perf_counter()
-        self.host_overlap_ms += (t1 - self._t_dispatched) * 1e3
-        # navilint: sync-ok chunk boundary -- the host scheduler branches on liveness between device chunks (one sync per chunk by design)
-        live = np.asarray(self._live_pending)
+        self._timing["host_overlap_ms"] += (t1 - self._t_dispatched) * 1e3
+        with span("navix.wait", self._timing, "device_wait_ms"):
+            # navilint: sync-ok chunk boundary -- the host scheduler branches on liveness between device chunks (one sync per chunk by design)
+            live = np.asarray(self._live_pending)
         self._live_pending = None
-        t2 = time.perf_counter()
-        self.device_wait_ms += (t2 - t1) * 1e3
-        self._t_wait_end = t2
-        self.n_chunks += 1
+        self._t_wait_end = time.perf_counter()
+        self._timing["n_chunks"] += 1
         return live
 
     def step(self, n_steps: int) -> np.ndarray:
@@ -402,20 +435,23 @@ class LaneBatch:
                                         n_steps, self.sigj, self.efsj)
 
     def timing(self) -> dict:
-        """Cumulative host-vs-device split over every stepped chunk."""
-        return {"n_chunks": self.n_chunks,
-                "host_gap_ms": self.host_gap_ms,
-                "host_overlap_ms": self.host_overlap_ms,
-                "device_wait_ms": self.device_wait_ms}
+        """Cumulative counters over every stepped chunk and finalize:
+        the host-vs-device split (``host_gap_ms``: host work the device
+        waited for, wait -> dispatch; ``host_overlap_ms``: host work
+        hidden behind the chunk in flight, dispatch -> wait;
+        ``device_wait_ms``: blocked on the device, the ``navix.wait``
+        span), ``n_chunks``, and the exact-tier re-rank (``rerank_ms``
+        over ``n_rerank`` finalizes, the ``navix.rerank`` span)."""
+        return dict(self._timing)
 
     def reset_timing(self) -> None:
-        """Zero the chunk counters and re-anchor the gap clock. A reused
+        """Zero the counters and re-anchor the gap clock. A reused
         batch (the closed-queue engine keeps LaneBatches across drains --
         parked-state allocation + mesh placement is the dominant per-drain
         setup cost on sharded backends) would otherwise charge the idle
         time between drains as host_gap."""
-        self.n_chunks = 0
-        self.host_gap_ms = self.host_overlap_ms = self.device_wait_ms = 0.0
+        self._timing.update(n_chunks=0, host_gap_ms=0.0, host_overlap_ms=0.0,
+                            device_wait_ms=0.0, rerank_ms=0.0, n_rerank=0)
         self._t_wait_end = time.perf_counter()
 
     def finalize(self, alive) -> tuple[np.ndarray, np.ndarray]:
@@ -427,16 +463,35 @@ class LaneBatch:
         (searched on int8 codes) is exactly re-ranked against the host
         f32 tier, lane-vectorized, so every driver's ``[:k]`` slice of a
         finalized lane is already exact-ordered. Parked/free lanes are
-        all ``-1`` and stay all ``-1`` through the re-rank."""
-        ids, dists = self.backend.finalize(self.st, self.udc, alive)
-        # navilint: sync-ok THE declared finalize boundary -- results cross to host exactly once per finalize
-        ids, dists = np.asarray(ids), np.asarray(dists)
+        all ``-1`` and stay all ``-1`` through the re-rank.
+
+        The per-lane work counters (``SearchStats``: iterations, distance
+        computations, branch picks) come back in the same transfer and
+        are kept as :attr:`stats` (None on a sharded backend)."""
+        fin = self.backend.finalize(self.st, self.udc, alive)
+        # navilint: sync-ok THE declared finalize boundary -- ids, dists and stats cross to host in one transfer per finalize
+        ids, dists, self.stats = jax.device_get(fin)
         exact = self.backend.exact
         if exact is not None:
             # exact-tier re-rank: host-side numpy at the same finalize
             # boundary (prepped queries already mirrored in Qh)
-            dists, ids = exact.rerank_many(self.Qh, ids, ids.shape[1])
+            with span("navix.rerank", self._timing, "rerank_ms"):
+                dists, ids = exact.rerank_many(self.Qh, ids, ids.shape[1])
+            self._timing["n_rerank"] += 1
         return ids, dists
+
+    def work(self, i: int) -> dict:
+        """Lane ``i``'s work counters from the last :meth:`finalize`, as
+        the ``Response`` fields ``iters`` (loop iterations), ``dc``
+        (distance computations, lower + upper level) and ``picks`` (times
+        onehop-s / directed / blind was chosen); empty on a sharded
+        backend, whose finalize returns beams only."""
+        st = self.stats
+        if st is None:
+            return {}
+        return {"iters": int(st.iters[i]),
+                "dc": int(st.t_dc[i] + st.upper_dc[i]),
+                "picks": tuple(int(x) for x in st.picks[i])}
 
     def evict(self, lane_ids) -> None:
         """Park the given lanes (one device call) and free them. Parked

@@ -19,6 +19,11 @@ advances in ``step_iters``-sized chunks, and between chunks it
 4. dispatches the next chunk asynchronously on donated state buffers
    and resolves the finalized futures while it runs.
 
+Each tick is a ``navix.tick`` step in a profile, and each of its steps a
+``navix.*`` span (``repro.serving.spans``); ``submit`` runs inside
+``navix.submit``. A response's ``queue_ms`` runs from enqueue to the
+admission that popped it, and ``exec_ms`` from there to the answer.
+
 Shard liveness is heartbeat-derived (:class:`HeartbeatMonitor`): the
 alive mask is recomputed from per-shard heartbeat staleness at every
 finalize, so a straggler shard flips responses to ``degraded``
@@ -41,6 +46,7 @@ from collections import OrderedDict, deque
 from concurrent.futures import Future, InvalidStateError
 from typing import Any, Callable, Optional
 
+import jax
 import numpy as np
 
 from repro.api.db import NavixDB
@@ -49,6 +55,7 @@ from repro.query.operators import output_table, split_pipeline
 from repro.serving.engine import Response, canonical_plan, resolve_alive
 from repro.serving.lanes import LaneBatch
 from repro.serving.queues import ServiceClosed, SubmissionQueue
+from repro.serving.spans import span
 
 
 @dataclasses.dataclass
@@ -69,7 +76,7 @@ class _Pending:
                                  # time; later cache hits pay 0)
     deadline: Optional[float]
     t_enqueue: float
-    t_start: float = 0.0         # set at lane admission
+    t_start: float = 0.0         # set at lane admission (after the pop)
     qrow: Optional[np.ndarray] = None
     sel_row: Optional[np.ndarray] = None
 
@@ -137,6 +144,8 @@ class SearchService:
         self.n_timeout = 0                       # guarded-by: _lat_lock
         self.n_partial = 0                       # guarded-by: _lat_lock
         self._lat = deque(maxlen=window)         # guarded-by: _lat_lock  (total ms, rolling)
+        self.submit_max_ms = 0.0                 # guarded-by: _lat_lock  (slowest submit)
+        self._n_ticks = 0
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
         self._draining = False
@@ -155,7 +164,21 @@ class SearchService:
         Raises :class:`QueueFull` under ``reject`` backpressure (or after
         ``block_timeout`` seconds under ``block``), :class:`ServiceClosed`
         after shutdown, ``ValueError`` if the plan exceeds the service's
-        fixed program (k/efs caps, heuristic, target index)."""
+        fixed program (k/efs caps, heuristic, target index).
+
+        Runs inside a ``navix.submit`` span; its wall time feeds
+        ``gauges()["submit_max_ms"]``, the slowest submit so far."""
+        t0 = time.perf_counter()
+        try:
+            with span("navix.submit"):
+                return self._submit(query, plan, k, deadline_s,
+                                    block_timeout)
+        finally:
+            ms = (time.perf_counter() - t0) * 1e3
+            with self._lat_lock:
+                self.submit_max_ms = max(self.submit_max_ms, ms)
+
+    def _submit(self, query, plan, k, deadline_s, block_timeout) -> Future:
         if self.error is not None:
             raise ServiceClosed(
                 f"device loop failed: {self.error!r}") from self.error
@@ -195,10 +218,11 @@ class SearchService:
                     self._sel_cache[s] = (self.lanes.backend.full_row(),
                                           1.0, 0.0)
                 else:
-                    qres = self.db.prefilter(s)
-                    self._sel_cache[s] = (
-                        self.lanes.backend.pack_row(qres.mask),
-                        qres.selectivity, qres.seconds * 1e3)
+                    with span("navix.prefilter"):
+                        qres = self.db.prefilter(s)
+                        self._sel_cache[s] = (
+                            self.lanes.backend.pack_row(qres.mask),
+                            qres.selectivity, qres.seconds * 1e3)
                 row, sigma, pf_ms = self._sel_cache[s]
                 while len(self._sel_cache) > self.sel_cache_size:
                     self._sel_cache.popitem(last=False)
@@ -208,8 +232,9 @@ class SearchService:
                 pf_ms = 0.0
             rid = self._next_rid
             self._next_rid += 1
-        qrow = np.asarray(self.entry.index._prep_query(
-            np.asarray(query, np.float32)[None]), np.float32)[0]
+        with span("navix.prep_query"):
+            qrow = np.asarray(self.entry.index._prep_query(
+                np.asarray(query, np.float32)[None]), np.float32)[0]
         now = self.clock()
         ddl_s = deadline_s if deadline_s is not None \
             else self.default_deadline_s
@@ -219,8 +244,9 @@ class SearchService:
                         t_enqueue=now, qrow=qrow, sel_row=row)
         self._open.add(pend.fut)
         pend.fut.add_done_callback(self._open.discard)
-        self.queue.put(sigma, pend.deadline, pend,
-                       timeout=block_timeout, now=now)
+        with span("navix.enqueue"):
+            self.queue.put(sigma, pend.deadline, pend,
+                           timeout=block_timeout, now=now)
         with self._lat_lock:
             self.n_submitted += 1
         return pend.fut
@@ -278,99 +304,117 @@ class SearchService:
         convergence takes precedence, matching the synchronous order
         where the step emitted it before the deadline check could run.
         """
-        now = self.clock() if now is None else now
-        worked = False
+        self._n_ticks += 1
+        with jax.profiler.StepTraceAnnotation("navix.tick",
+                                              step_num=self._n_ticks):
+            now = self.clock() if now is None else now
+            worked = False
 
-        # 1. queue-side expiry: deadline passed before a lane freed up
-        # (host-only -- runs while the previous chunk is still in flight)
-        for it in self.queue.expire(now):
-            self._emit_timeout(it.meta, now)
-            worked = True
+            # 1. queue-side expiry: deadline passed before a lane freed up
+            # (host-only -- runs while the previous chunk is still in
+            # flight)
+            with span("navix.expire"):
+                for it in self.queue.expire(now):
+                    self._emit_timeout(it.meta, now)
+                    worked = True
 
-        # 2. synchronize on the chunk dispatched last tick (the ONE
-        # device sync per tick)
-        live = self.lanes.step_wait() if self.lanes.step_pending else None
-        t_done = self.clock()
+            # 2. synchronize on the chunk dispatched last tick (the ONE
+            # device sync per tick; LaneBatch.step_wait's navix.wait span)
+            live = self.lanes.step_wait() if self.lanes.step_pending \
+                else None
+            t_done = self.clock()
 
-        # 3. one finalize covers both converged and overdue lanes.
-        # Finalize FIRST for overdue lanes: a beam that already holds k
-        # valid candidates is a usable best-effort answer ("partial");
-        # anything less resolves to "timeout" with ALL ids -1 -- a
-        # truncated list would silently read as a full top-k. Evicted
-        # lanes park on device (live=False) so the next admit reuses
-        # them. Responses are built here but resolved AFTER the next
-        # chunk is dispatched (step 6).
-        conv = ([] if live is None else
-                [i for i in self.lanes.occupied() if not live[i]])
-        overdue = [i for i in self.lanes.occupied()
-                   if i not in conv
-                   and self.lanes.meta[i].deadline is not None
-                   and self.lanes.meta[i].deadline < now]
-        rows: list[tuple] = []
-        if conv or overdue:
-            alive = self._alive()
-            degraded = self.lanes.n_shards > 0 and not alive.all()
-            ids, dists = self.lanes.finalize(alive)
-            for i in conv:
-                pend = self.lanes.meta[i]
-                rows.append((pend, Response(
-                    rid=pend.rid, ids=ids[i, :pend.k],
-                    dists=dists[i, :pend.k],
-                    queue_ms=(pend.t_start - pend.t_enqueue) * 1e3,
-                    exec_ms=(t_done - pend.t_start) * 1e3,
-                    prefilter_ms=pend.pf_ms, sigma=pend.sigma,
-                    degraded=degraded, status="ok")))
-                self.lanes.release(i)
-            for i in overdue:
-                pend = self.lanes.meta[i]
-                got = ids[i, :pend.k]
-                if (got >= 0).all():
-                    rows.append((pend, Response(
-                        rid=pend.rid, ids=got, dists=dists[i, :pend.k],
-                        queue_ms=(pend.t_start - pend.t_enqueue) * 1e3,
-                        exec_ms=(now - pend.t_start) * 1e3,
-                        prefilter_ms=pend.pf_ms, sigma=pend.sigma,
-                        degraded=degraded, status="partial")))
-                else:
-                    rows.append((pend, None))    # timeout, built in step 6
-            self.lanes.evict(overdue)
-            worked = True
-
-        # 4. admit from the queue into free lanes (the running lanes'
-        # median sigma anchors the selectivity bin, keeping the fused
-        # batch regime-coherent)
-        n_free = self.lanes.free_count()
-        if n_free:
-            occ = self.lanes.occupied()
-            # navilint: sync-ok sigh is host-side scheduler state (sigma history), never a traced value
-            prefer = (float(np.median(self.lanes.sigh[occ]))
-                      if occ else None)
-            batch = self.queue.pop_batch(n_free, prefer)
-            if batch:
-                entries = []
-                for it in batch:
-                    pend = it.meta
-                    pend.t_start = now
-                    entries.append((pend, pend.qrow, pend.sel_row,
-                                    pend.sigma, pend.efs))
-                self.lanes.admit(entries)
+            # 3. one finalize covers both converged and overdue lanes.
+            # Finalize FIRST for overdue lanes: a beam that already holds
+            # k valid candidates is a usable best-effort answer
+            # ("partial"); anything less resolves to "timeout" with ALL
+            # ids -1 -- a truncated list would silently read as a full
+            # top-k. Evicted lanes park on device (live=False) so the next
+            # admit reuses them. Responses are built here but resolved
+            # AFTER the next chunk is dispatched (step 6).
+            conv = ([] if live is None else
+                    [i for i in self.lanes.occupied() if not live[i]])
+            overdue = [i for i in self.lanes.occupied()
+                       if i not in conv
+                       and self.lanes.meta[i].deadline is not None
+                       and self.lanes.meta[i].deadline < now]
+            rows: list[tuple] = []
+            if conv or overdue:
+                with span("navix.finalize", lanes=len(conv) + len(overdue)):
+                    alive = self._alive()
+                    degraded = self.lanes.n_shards > 0 and not alive.all()
+                    ids, dists = self.lanes.finalize(alive)
+                    for i in conv:
+                        pend = self.lanes.meta[i]
+                        rows.append((pend, Response(
+                            rid=pend.rid, ids=ids[i, :pend.k],
+                            dists=dists[i, :pend.k],
+                            queue_ms=(pend.t_start - pend.t_enqueue) * 1e3,
+                            exec_ms=(t_done - pend.t_start) * 1e3,
+                            prefilter_ms=pend.pf_ms, sigma=pend.sigma,
+                            degraded=degraded, status="ok",
+                            **self.lanes.work(i))))
+                        self.lanes.release(i)
+                    for i in overdue:
+                        pend = self.lanes.meta[i]
+                        got = ids[i, :pend.k]
+                        if (got >= 0).all():
+                            rows.append((pend, Response(
+                                rid=pend.rid, ids=got,
+                                dists=dists[i, :pend.k],
+                                queue_ms=(pend.t_start - pend.t_enqueue)
+                                * 1e3,
+                                exec_ms=(now - pend.t_start) * 1e3,
+                                prefilter_ms=pend.pf_ms, sigma=pend.sigma,
+                                degraded=degraded, status="partial",
+                                **self.lanes.work(i))))
+                        else:
+                            # timeout, built in step 6
+                            rows.append((pend, None))
+                with span("navix.evict", lanes=len(overdue)):
+                    self.lanes.evict(overdue)
                 worked = True
 
-        # 5. dispatch the next chunk (async, donated state). Always
-        # chunked (never run-to-convergence): a live loop must return to
-        # the queue between chunks.
-        if self.lanes.occupied_count():
-            self.lanes.step_async(self.step_iters)
-            worked = True
+            # 4. admit from the queue into free lanes (the running lanes'
+            # median sigma anchors the selectivity bin, keeping the fused
+            # batch regime-coherent). A lane's start is stamped after the
+            # pop, so a request enqueued while this tick waited on the
+            # device never starts before it was enqueued (queue_ms >= 0).
+            n_free = self.lanes.free_count()
+            if n_free:
+                occ = self.lanes.occupied()
+                # navilint: sync-ok sigh is host-side scheduler state (sigma history), never a traced value
+                prefer = (float(np.median(self.lanes.sigh[occ]))
+                          if occ else None)
+                batch = self.queue.pop_batch(n_free, prefer)
+                if batch:
+                    t_admit = self.clock()
+                    entries = []
+                    for it in batch:
+                        pend = it.meta
+                        pend.t_start = t_admit
+                        entries.append((pend, pend.qrow, pend.sel_row,
+                                        pend.sigma, pend.efs))
+                    self.lanes.admit(entries)    # the navix.admit span
+                    worked = True
 
-        # 6. resolve futures -- host-only, overlapped with the chunk
-        # dispatched above (Future callbacks run in this thread)
-        for pend, resp in rows:
-            if resp is None:
-                self._emit_timeout(pend, now)
-            else:
-                self._resolve(pend, resp)
-        return worked
+            # 5. dispatch the next chunk (async, donated state). Always
+            # chunked (never run-to-convergence): a live loop must return
+            # to the queue between chunks.
+            if self.lanes.occupied_count():
+                with span("navix.dispatch"):
+                    self.lanes.step_async(self.step_iters)
+                worked = True
+
+            # 6. resolve futures -- host-only, overlapped with the chunk
+            # dispatched above (Future callbacks run in this thread)
+            with span("navix.resolve", n=len(rows)):
+                for pend, resp in rows:
+                    if resp is None:
+                        self._emit_timeout(pend, now)
+                    else:
+                        self._resolve(pend, resp)
+            return worked
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> "SearchService":
@@ -474,17 +518,20 @@ class SearchService:
     # -- observability ------------------------------------------------------
     def gauges(self) -> dict:
         """Live service gauges: queue depth/backpressure state, in-flight
-        lanes, completion counters, rolling p50/p99 latency, and the
-        cumulative host-vs-device chunk split (``chunks``: host work the
-        device waited for vs host work hidden behind in-flight chunks vs
-        time blocked on the device)."""
+        lanes, completion counters, rolling p50/p99 latency, the slowest
+        ``submit`` call so far (``submit_max_ms``: a process-wide stall
+        shows here), and the lanes' cumulative counters (``chunks``:
+        ``LaneBatch.timing()`` -- host work the device waited for vs host
+        work hidden behind in-flight chunks vs time blocked on the
+        device, and the exact-tier re-rank)."""
         g = {"queue": self.queue.gauges(),
              "in_flight": self.lanes.occupied_count(),
              "lanes": self.lanes.bsz,
              "chunks": self.lanes.timing()}
         with self._lat_lock:
             g.update(submitted=self.n_submitted, done=self.n_done,
-                     timeouts=self.n_timeout, partials=self.n_partial)
+                     timeouts=self.n_timeout, partials=self.n_partial,
+                     submit_max_ms=self.submit_max_ms)
             lat = list(self._lat)
         if lat:
             arr = np.asarray(lat)
