@@ -45,9 +45,11 @@ asserts exactly equal (ids, dists) and stats.
 The distance primitive is :func:`batch_gather_dist`, which routes through
 ``repro.kernels.ops.gather_distance_batch`` -- the batched Pallas
 gather+distance kernel on TPU (interpret mode under
-``REPRO_FORCE_PALLAS=1``), the XLA reference elsewhere. Set
-``REPRO_ENGINE_GATHER=xla`` to pin the pure-jnp path; the choice is baked
-at trace time, so set the env var before the first engine call.
+``REPRO_FORCE_PALLAS=1``), the XLA reference elsewhere. The second hop's
+candidate test (:func:`batch_eligible`) routes the same way, through
+``repro.kernels.ops.second_hop_eligible``. Set ``REPRO_ENGINE_GATHER=xla``
+to pin the pure-jnp path for both; the choice is baked at trace time, so
+set the env var before the first engine call.
 
 The ``engine_*`` stepping API at the bottom decomposes the same loop into
 resumable chunks (park / refill / step / finalize) for the serving tier's
@@ -127,6 +129,19 @@ def batch_gather_dist(Q: jax.Array, vectors: jax.Array, ids: jax.Array,
         return ops.quantized_gather_distance_batch(Q, codes, vectors.scale,
                                                    ids, metric)
     return ops.gather_distance_batch(Q, vectors, ids, metric)
+
+
+def batch_eligible(sel2: jax.Array, visited: jax.Array,
+                   ids: jax.Array) -> jax.Array:
+    """The second hop's candidate test, ``ids >= 0 & bit(sel2[b], ids) &
+    ~bit(visited[b], ids)`` -> bool[B, L]: the Pallas kernel of
+    ``repro.kernels.ops.second_hop_eligible`` where that layer takes it,
+    the two lane-wise bit tests under ``REPRO_ENGINE_GATHER=xla`` (the
+    same pin as :func:`batch_gather_dist`)."""
+    from repro.kernels import ops, ref
+    if gather_backend() == "xla":
+        return ref.second_hop_eligible(sel2, visited, ids)
+    return ops.second_hop_eligible(sel2, visited, ids)
 
 
 def _take_first_batch(elig: jax.Array, values: jax.Array, width: int,
@@ -352,11 +367,10 @@ def _loop_fns(graph: HnswGraph, Q: jax.Array, sel2: jax.Array,
             nb2 = lower[jnp.maximum(parents, 0)]           # [B, M, M]
             flat = jnp.where((parents >= 0)[:, :, None], nb2,
                              -1).reshape(bsz, -1)
-            elig = flat >= 0
+            # one call tests S and the visited set (one kernel on the
+            # TPU), so both count under one scope
             with jax.named_scope("navix.semimask"):
-                elig = elig & bitset.test_batch(sel2, flat)
-            with jax.named_scope("navix.visited"):
-                elig = elig & ~bitset.test_batch(visited1, flat)
+                elig = batch_eligible(sel2, visited1, flat)
             cand = _take_first_batch(elig, flat, 2 * k2)   # over-take ...
             cand = dedupe(cand)                            # ... dedupe ...
             cand2 = _take_first_batch(cand >= 0, cand, k2,

@@ -10,6 +10,9 @@ already lives* (in buffer-manager frames, Section 4.2.1). The TPU analogue:
                        candidate rows stream HBM->VMEM and the distance is
                        computed in VMEM without materializing the gather
                        (the in-buffer-manager zero-copy optimization)
+  bit_test          -- the second hop's eligibility: each lane's selected
+                       set and visited set tested for all its two-hop ids
+                       in one pass, both bitsets resident in VMEM
   quantized         -- int8-code distance with per-vector scales
                        (DiskANN-regime search, Section 5.8)
   segment_sum       -- CSR-sorted segment sum as one-hot MXU matmuls

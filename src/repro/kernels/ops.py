@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
+from repro.kernels.bit_test import LANES, second_hop_eligible_pallas
 from repro.kernels.distance_matrix import distance_matrix_pallas
 from repro.kernels.gather_distance import (
     gather_distance_batch_pallas, gather_distance_pallas,
@@ -114,6 +115,29 @@ def quantized_gather_distance_batch(Q, codes, scale, ids, metric: str = "l2"):
     return quantized_gather_distance_batch_pallas(Q, codes, scale, ids,
                                                   metric,
                                                   interpret=not _on_tpu())
+
+
+#: the most 128-word rows a lane's bitset may have for
+#: :func:`second_hop_eligible` to take the Pallas kernel, whose cost grows
+#: with the rows where the XLA gathers' does not: on one v5e (64 lanes x
+#: 4,096 ids) the kernel takes 0.04 ms at 16 rows and 6.4 ms at 1,024, the
+#: XLA gathers 6.4-7.7 ms at any width, and the two cross near 1,200 rows
+#: (n about 4.9M; PERF.md)
+BIT_TEST_MAX_ROWS = 1024
+
+
+def second_hop_eligible(sel, visited, ids):
+    """The second hop's candidate test: ``ids >= 0 & bit(sel[b], ids) &
+    ~bit(visited[b], ids)`` -> bool[B, L], for per-lane bitsets
+    ``sel``/``visited`` ``[B, W]`` and ids ``[B, L]``.
+
+    One Pallas pass with both bitsets in VMEM while W fits
+    ``BIT_TEST_MAX_ROWS`` rows of 128 words; the two XLA bit tests
+    otherwise and off the TPU."""
+    if not _use_pallas() or -(-sel.shape[1] // LANES) > BIT_TEST_MAX_ROWS:
+        return ref.second_hop_eligible(sel, visited, ids)
+    return second_hop_eligible_pallas(sel, visited, ids,
+                                      interpret=not _on_tpu())
 
 
 def quantized_distance_matrix(Q, codes, scale, metric: str = "l2",

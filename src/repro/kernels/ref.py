@@ -9,6 +9,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core import bitset
+
 
 def distance_matrix(Q: jax.Array, X: jax.Array, metric: str) -> jax.Array:
     """f32[b,n] distances; see repro.core.distances.dist_matrix."""
@@ -133,3 +135,12 @@ def csr_segment_sum(messages: jax.Array, dst_sorted: jax.Array,
     contrib = jnp.where((dst_sorted >= 0)[:, None], messages, 0)
     return jax.ops.segment_sum(contrib.astype(jnp.float32), safe,
                                num_segments=n + 1)[:n]
+
+
+def second_hop_eligible(sel: jax.Array, visited: jax.Array,
+                        ids: jax.Array) -> jax.Array:
+    """bool[B, L]: ``ids >= 0 & bit(sel[b], ids) & ~bit(visited[b], ids)``,
+    as the batched engine's second hop composed it from two lane-wise bit
+    tests (one single-word gather per id and bitset)."""
+    return ((ids >= 0) & bitset.test_batch(sel, ids)
+            & ~bitset.test_batch(visited, ids))

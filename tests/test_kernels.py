@@ -4,7 +4,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import bitset
 from repro.kernels import ref
+from repro.kernels.bit_test import second_hop_eligible_pallas
 from repro.kernels.distance_matrix import distance_matrix_pallas
 from repro.kernels.gather_distance import (
     gather_distance_batch_pallas, gather_distance_pallas,
@@ -200,6 +202,73 @@ def test_quantized_gather_ops_pad_odd_shapes(monkeypatch):
         finb = np.isfinite(eb)
         np.testing.assert_array_equal(np.isinf(gb), np.isinf(eb))
         np.testing.assert_allclose(gb[finb], eb[finb], rtol=1e-4, atol=1e-4)
+
+
+def _bits(shape, fill):
+    if fill == "random":
+        return RNG.integers(0, 2**32, size=shape, dtype=np.uint64).astype(
+            np.uint32)
+    return np.full(shape, 0xFFFFFFFF if fill == "ones" else 0, np.uint32)
+
+
+@pytest.mark.parametrize("case,n,b,l", [
+    ("random", 65_536, 3, 4096),      # the cells' W: 16 rows, unrolled
+    ("random", 163_840, 2, 512),      # 40 rows: the rolled row loop
+    ("random", 5_000, 4, 300),        # W 157 and L 300, both padded
+    ("edges", 40_000, 2, 256),        # word and row boundaries, n - 1
+    ("fills", 9_000, 5, 256),         # all-zero and all-one lanes
+])
+def test_second_hop_eligible_sweep(case, n, b, l):
+    """The kernel is bitwise the two bit tests it replaces."""
+    w = bitset.n_words(n)
+    ids = RNG.integers(0, n, size=(b, l)).astype(np.int32)
+    ids[RNG.random((b, l)) < 0.2] = -1
+    sel, vis = _bits((b, w), "random"), _bits((b, w), "random")
+    if case == "edges":
+        edge = np.array([0, 31, 32, 33, 4095, 4096, 4097, 8191, 8192,
+                         n - 32, n - 2, n - 1, -1], np.int32)
+        ids[:, :edge.size] = edge
+    if case == "fills":
+        for lane, (fs, fv) in enumerate([("zeros", "random"),
+                                         ("ones", "zeros"),
+                                         ("ones", "ones"),
+                                         ("ones", "random"),
+                                         ("random", "zeros")]):
+            sel[lane], vis[lane] = _bits(w, fs), _bits(w, fv)
+    sel, vis, ids = jnp.asarray(sel), jnp.asarray(vis), jnp.asarray(ids)
+    got = second_hop_eligible_pallas(sel, vis, ids, interpret=True)
+    exp = ref.second_hop_eligible(sel, vis, ids)
+    assert got.dtype == exp.dtype and got.shape == exp.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+    if case == "fills":
+        assert not np.asarray(got)[0].any() and not np.asarray(got)[2].any()
+        np.testing.assert_array_equal(np.asarray(got)[1],
+                                      np.asarray(ids)[1] >= 0)
+
+
+@pytest.mark.parametrize("force,over", [("1", 0), ("1", 1), ("0", 0)])
+def test_second_hop_eligible_takes_kernel_up_to_the_row_constant(
+        monkeypatch, force, over):
+    """The kernel serves bitsets of up to ``BIT_TEST_MAX_ROWS`` rows of
+    128 words; a wider bitset, or a host without the TPU (and no
+    ``REPRO_FORCE_PALLAS``), takes the XLA bit tests."""
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", force)
+    from repro.kernels import ops
+    calls = []
+
+    def spy(sel, vis, ids, interpret):
+        calls.append(sel.shape)
+        return ref.second_hop_eligible(sel, vis, ids)
+
+    monkeypatch.setattr(ops, "second_hop_eligible_pallas", spy)
+    w = (ops.BIT_TEST_MAX_ROWS + over) * 128
+    sel = jnp.asarray(_bits((2, w), "random"))
+    vis = jnp.asarray(_bits((2, w), "random"))
+    ids = jnp.asarray(RNG.integers(-1, 32 * w, size=(2, 256)), jnp.int32)
+    got = ops.second_hop_eligible(sel, vis, ids)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(
+        ref.second_hop_eligible(sel, vis, ids)))
+    assert calls == ([] if over or force == "0" else [(2, w)])
 
 
 @pytest.mark.parametrize("e,d,n,bn,be", [

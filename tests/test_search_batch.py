@@ -8,6 +8,7 @@ stats must match exactly (not approximately)."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import bitset
@@ -114,3 +115,46 @@ def test_navix_search_many_engines_agree(index, queries):
     np.testing.assert_array_equal(np.asarray(a.dists), np.asarray(b.dists))
     with pytest.raises(ValueError, match="engine"):
         index.search_many(queries[:2], k=4, engine="nope")
+
+
+@pytest.mark.parametrize("heuristic", ["adaptive_local", "blind",
+                                       "directed"])
+def test_bit_test_kernel_leaves_the_search_unchanged(index, queries,
+                                                     monkeypatch, heuristic):
+    """With the Pallas kernels forced (interpret mode), the second hop's
+    eligibility kernel gives the ids, dists and stats of the two XLA bit
+    tests, for per-lane semimasks whose selectivities send lanes into the
+    directed and the blind branch."""
+    from repro.kernels import ops
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    rng = np.random.default_rng(17)
+    sigmas = np.array([0.6, 0.2, 0.05, 0.02])
+    sel = bitset.pack(jnp.asarray(
+        rng.random((sigmas.size, index.graph.n)) < sigmas[:, None]))
+    Q = jnp.asarray(queries[:sigmas.size])
+    params = _params(index, heuristic=heuristic)
+    calls = []
+    kernel = ops.second_hop_eligible_pallas
+
+    def spy(*args, **kw):
+        calls.append(1)
+        return kernel(*args, **kw)
+
+    runs = []
+    for max_rows in (ops.BIT_TEST_MAX_ROWS, 0):
+        monkeypatch.setattr(ops, "BIT_TEST_MAX_ROWS", max_rows)
+        monkeypatch.setattr(ops, "second_hop_eligible_pallas", spy)
+        jax.clear_caches()        # the dispatch is baked in at trace time
+        runs.append(search_many(index.graph, Q, sel, params))
+    jax.clear_caches()
+    assert len(calls) == 1        # the kernel ran in the first run only
+    kern, xla = runs
+    np.testing.assert_array_equal(np.asarray(kern.ids), np.asarray(xla.ids))
+    np.testing.assert_array_equal(np.asarray(kern.dists),
+                                  np.asarray(xla.dists))
+    for field in ("iters", "t_dc", "s_dc", "upper_dc", "picks"):
+        np.testing.assert_array_equal(np.asarray(getattr(kern.stats, field)),
+                                      np.asarray(getattr(xla.stats, field)))
+    picks = np.asarray(kern.stats.picks).sum(axis=0)
+    if heuristic == "adaptive_local":
+        assert picks[1] > 0 and picks[2] > 0, picks

@@ -24,6 +24,7 @@ from repro.core.graph import HnswGraph
 from repro.core.quantize import QuantizedStore
 from repro.core.search import SearchParams
 from repro.kernels import ops
+from repro.kernels.bit_test import second_hop_eligible_pallas
 from repro.kernels.distance_matrix import distance_matrix_pallas
 from repro.kernels.gather_distance import (
     gather_distance_batch_pallas, gather_distance_pallas,
@@ -109,24 +110,20 @@ def test_distance_matrix_kernels_compile(one_chip, d, quant):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("quant", [False, True])
-def test_engine_steps_compiles_with_kernel(one_chip, monkeypatch, quant):
-    """The served engine step at 1M x 768 (PAPER_INDEX degrees, k=100,
-    efs=200, 64 lanes) compiles for the chip with the gather kernel in
-    its loop, and never copies the vector store (a per-iteration
-    relayout or pad of it would show as a store-shaped op)."""
-    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    monkeypatch.delenv(sb.GATHER_ENV, raising=False)
-    d, m_l, m_u, n_up = 768, 64, 32, N // 20
+def _engine_step_text(one_chip, n, quant, d=768):
+    """The optimized HLO of the served chunk program (PAPER_INDEX degrees,
+    k=100, efs=200, 64 lanes, 32 steps) at n rows, compiled for the chip
+    with the Pallas paths on."""
+    m_l, m_u, n_up = 64, 32, n // 20
     params = SearchParams(k=100, efs=200, heuristic=3, metric="cos")
     if quant:
-        vectors = QuantizedStore(codes=_sds((N, d), jnp.int8, one_chip),
-                                 scale=_sds((N,), jnp.float32, one_chip))
+        vectors = QuantizedStore(codes=_sds((n, d), jnp.int8, one_chip),
+                                 scale=_sds((n,), jnp.float32, one_chip))
     else:
-        vectors = _sds((N, d), jnp.float32, one_chip)
+        vectors = _sds((n, d), jnp.float32, one_chip)
     graph = HnswGraph(
-        lower=_sds((N, m_l), jnp.int32, one_chip),
-        lower_deg=_sds((N,), jnp.int32, one_chip),
+        lower=_sds((n, m_l), jnp.int32, one_chip),
+        lower_deg=_sds((n,), jnp.int32, one_chip),
         upper=_sds((n_up, m_u), jnp.int32, one_chip),
         upper_deg=_sds((n_up,), jnp.int32, one_chip),
         upper_ids=_sds((n_up,), jnp.int32, one_chip),
@@ -134,13 +131,69 @@ def test_engine_steps_compiles_with_kernel(one_chip, monkeypatch, quant):
         vectors=vectors)
     state = jax.tree.map(
         lambda x: _sds(x.shape, x.dtype, one_chip),
-        jax.eval_shape(lambda: sb.parked_state(N, B, params)))
-    words = -(-N // 32)
-    compiled = sb.engine_steps_overlap.lower(
+        jax.eval_shape(lambda: sb.parked_state(n, B, params)))
+    words = -(-n // 32)
+    return sb.engine_steps_overlap.lower(
         graph, _sds((B, d), jnp.float32, one_chip),
         _sds((B, words), jnp.uint32, one_chip), state, params, 32,
         sigma_g=_sds((B,), jnp.float32, one_chip),
-        efs_lanes=_sds((B,), jnp.int32, one_chip)).compile()
-    text = compiled.as_text()
+        efs_lanes=_sds((B,), jnp.int32, one_chip)).compile().as_text()
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_engine_steps_compiles_with_kernel(one_chip, monkeypatch, quant):
+    """The served engine step at 1M x 768 compiles for the chip with the
+    gather kernel in its loop, and never copies the vector store (a
+    per-iteration relayout or pad of it would show as a store-shaped
+    op)."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.delenv(sb.GATHER_ENV, raising=False)
+    text = _engine_step_text(one_chip, N, quant)
     assert "tpu_custom_call" in text
-    assert not _store_copies(text, f"{'s8' if quant else 'f32'}[{N},{d}]")
+    assert not _store_copies(text, f"{'s8' if quant else 'f32'}[{N},768]")
+
+
+@pytest.mark.parametrize("n", [65_536, N])
+def test_second_hop_eligible_kernel_compiles(one_chip, n):
+    """The second hop's eligibility kernel at the cells' n and at 1M (W
+    31,250: padded to 245 rows of 128 words), 64 lanes x 64² ids."""
+    words = -(-n // 32)
+    tables = _sds((B, words), jnp.uint32, one_chip)
+    compiled = second_hop_eligible_pallas.lower(
+        tables, tables, _sds((B, 64 * 64), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _word_gathers(text: str, result: str, table: str) -> list[str]:
+    """Gathers of shape ``result`` whose table (first operand) has shape
+    ``table``: optimized HLO names operands without their types, so the
+    table's shape comes from its defining instruction."""
+    types = dict(re.findall(r"^\s*(?:ROOT )?(%\S+) = (\w+\[[\d,]*\])", text,
+                            re.M))
+    pat = re.compile(r"^\s*(?:ROOT )?%\S+ = " + re.escape(result)
+                     + r"\{[^}]*\} gather\((%[^,]+),", re.M)
+    return [op for op in pat.findall(text) if types.get(op) == table]
+
+
+def test_engine_step_tests_the_second_hop_in_one_kernel(one_chip,
+                                                        monkeypatch):
+    """At the cells' n (65,536: a lane's bitset is u32[2048]) the served
+    chunk program no longer gathers single words of S and of the visited
+    set for the second hop's 64 x 64 ids per lane: the eligibility kernel
+    does, under the ``navix.semimask`` scope."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.delenv(sb.GATHER_ENV, raising=False)
+    n, shapes = 65_536, ("u32[64,4096]", "u32[64,2048]")
+    text = _engine_step_text(one_chip, n, quant=False)
+    assert not _word_gathers(text, *shapes)
+    kernel = re.findall(r"^\s*%second_hop_eligible_pallas\S* = .*"
+                        r'op_name="([^"]*)"', text, re.M)
+    assert len(kernel) == 1 and "/navix.semimask/" in kernel[0]
+    # with the kernel's row limit at 0 the two XLA word gathers return
+    monkeypatch.setattr(ops, "BIT_TEST_MAX_ROWS", 0)
+    jax.clear_caches()
+    try:
+        text = _engine_step_text(one_chip, n, quant=False)
+    finally:
+        jax.clear_caches()
+    assert len(_word_gathers(text, *shapes)) == 2
