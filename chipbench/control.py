@@ -4,8 +4,8 @@
         [--seconds 51]
 
 For each seed it makes the corpus and the requests a run of ``--seconds``
-makes, and compares, as a run compares the system's answers (every
-request a run answers):
+makes (of a closed mix, the check set: the requests every run answers),
+and compares, as a run compares the system's answers:
 
 * ``control``: the reference put in the system's place, computed in
   bfloat16 (``reference.control_numbers``);
@@ -41,8 +41,9 @@ def readings(cfg: dict, mix: dict, gen, seed: int, seconds: float) -> dict:
     import traffic
 
     k, metric = int(cfg["k"]), cfg["metric"]
-    c = corpus_mod.make_corpus(cfg, seed)
-    count = traffic.request_count(mix, seconds)
+    c = corpus_mod.make_corpus(cfg, mix.get("data_seed", seed))
+    count = (int(mix["clients"]) * int(mix["recall_per_client"])
+             if "clients" in mix else traffic.request_count(mix, seconds))
     reqs = gen.make_requests(mix, c, cfg, seed, count + 1)[:-1]
     Q = np.stack([r.q for r in reqs])
     masks = np.stack([gen.mask(c, r.pred) for r in reqs])

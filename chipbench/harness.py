@@ -9,10 +9,13 @@
 and returns the result line. Set-up (``setup_s``) runs from process
 start to the window's opening: corpus, build, the compile of every
 served program (one warm-up request), and ``ramp_s`` of the cell's own
-traffic, which brings the lanes to steady occupancy. The window then
-measures for ``seconds``; requests still open at its close are waited
-for up to ``drain_s``; the device's peak memory is read; the service is
-shut down and freed; and the reference checks every answer.
+traffic, which brings the lanes to steady occupancy. A mix with
+``clients`` runs a closed loop (``traffic.ClosedLoop``), any other an
+open one (``traffic.OpenLoop``). The window then measures for
+``seconds``; requests still open at its close, and in a closed loop the
+rest of its check set, are waited for up to ``drain_s``; the device's
+peak memory is read; the service is shut down and freed; and the
+reference checks every answer.
 """
 
 from __future__ import annotations
@@ -132,14 +135,16 @@ def run_cell(cell_name: str, cfg: dict, mix: dict, gen, lim: dict,
     k, efs = int(cfg["k"]), int(cfg["efs"])
 
     t = time.perf_counter()
-    c = corpus_mod.make_corpus(cfg, seed)
+    # a mix with ``data_seed`` serves one corpus and index in every run
+    data_seed = mix.get("data_seed", seed)
+    c = corpus_mod.make_corpus(cfg, data_seed)
     count = traffic.request_count(mix, seconds)
     reqs = gen.make_requests(mix, c, cfg, seed, count + 1)
     warm, reqs = reqs[-1], reqs[:-1]
     say(f"corpus: n={c.n} d={c.X.shape[1]} seed={seed} "
         f"{time.perf_counter() - t:.2f} s; {len(reqs)} requests")
 
-    db, build_s = build_db(c, cfg, seed)
+    db, build_s = build_db(c, cfg, data_seed)
     say(f"build: {build_s:.2f} s ({c.n / build_s:.1f} rows/s); compiles "
         f"so far {compiles.count} ({compiles.seconds:.2f} s)")
     svc = db.serve(index="emb", k_cap=k, efs_cap=efs,
@@ -161,10 +166,19 @@ def run_cell(cell_name: str, cfg: dict, mix: dict, gen, lim: dict,
     say(f"warm-up: {time.perf_counter() - t:.2f} s; compiles "
         f"{compiles.count} ({compiles.seconds:.2f} s)")
 
-    offs = gen.open_offsets(float(mix["rate_qps"]), len(reqs),
-                            corpus_mod.rng_for(seed, 2))
-    t0 = time.perf_counter() + 0.2
-    loop = traffic.OpenLoop(submit, reqs, offs, t0, annotate).start()
+    closed = "clients" in mix
+    if closed:
+        t0 = time.perf_counter() + 0.2
+        t_close = t0 + float(mix["ramp_s"]) + seconds
+        loop = traffic.ClosedLoop(submit, reqs, int(mix["clients"]),
+                                  int(mix["recall_per_client"]), t0,
+                                  t_close, t_close + float(mix["drain_s"]),
+                                  annotate).start()
+    else:
+        offs = gen.open_offsets(float(mix["rate_qps"]), len(reqs),
+                                corpus_mod.rng_for(seed, 2))
+        t0 = time.perf_counter() + 0.2
+        loop = traffic.OpenLoop(submit, reqs, offs, t0, annotate).start()
     t_open = t0 + float(mix["ramp_s"])
     t_close = t_open + seconds
 
@@ -207,7 +221,8 @@ def run_cell(cell_name: str, cfg: dict, mix: dict, gen, lim: dict,
     failed = sum(1 for r in win if not r.answered)
     late = [r.lateness_s for r in win if not math.isnan(r.t_submit)]
     # the sender's lateness: how far the load generator ran behind
-    say(f"window: {len(win)} requests, {ok_in_window} answered in it, "
+    say(f"window: {len(win)} requests, {ok_in_window} answered in it "
+        f"({ok_in_window / seconds:.3f} q/s), "
         f"{failed} not answered; sender lateness p50 "
         f"{_percentile(late, 50) * 1e3:.3f} ms, max "
         f"{max(late, default=float('nan')) * 1e3:.3f} ms")
@@ -241,7 +256,10 @@ def run_cell(cell_name: str, cfg: dict, mix: dict, gen, lim: dict,
     }
     correct = all(v <= limit for v, limit in checks.values())
 
-    win_recall = [rec_of[id(r)] for r in win if id(r) in rec_of]
+    # recall: over the window's requests, or in a closed loop over its
+    # check set, which is the same whatever the speed
+    win_recall = [rec_of[id(r)] for r in (loop.check_set() if closed
+                                          else win) if id(r) in rec_of]
     e2e = {
         "qps": (ok_in_window / seconds, "queries/s"),
         "p90_ms": (_percentile(lat, 90) * 1e3, "ms"),
@@ -250,7 +268,8 @@ def run_cell(cell_name: str, cfg: dict, mix: dict, gen, lim: dict,
         "setup_s": (setup_s, "s"),
     }
     say(f"latency: p50 {_percentile(lat, 50) * 1e3:.1f} ms, p90 "
-        f"{e2e['p90_ms'][0]:.1f} ms over {len(lat)}; compiles total "
+        f"{e2e['p90_ms'][0]:.1f} ms over {len(lat)}; recall "
+        f"{e2e['recall'][0]:.6f} over {len(win_recall)}; compiles total "
         f"{compiles.count} ({compiles.seconds:.2f} s)")
 
     device_info = {"platform": dev.platform, "kind": dev.device_kind,

@@ -146,21 +146,36 @@ def _clip(e: Ev, lo: float, hi: float) -> Optional[tuple[float, float]]:
     return (s, t) if t > s else None
 
 
-def host_activity(events: list[Ev], s: float, t: float) -> str:
-    """What the host was doing in ``[s, t]``: the shortest host event
-    covering at least half of it, else the one covering most."""
-    best, best_key = "no host event", None
-    for e in events:
-        if is_device(e.plane) or e.name == WINDOW_SPAN:
-            continue
-        c = _clip(e, s, t)
-        if c is None:
-            continue
-        cover = (c[1] - c[0]) / (t - s)
-        key = (cover >= 0.5, -e.dur if cover >= 0.5 else cover)
-        if best_key is None or key > best_key:
-            best, best_key = e.name, key
-    return best
+def host_activity(events: list[Ev],
+                  spans: list[tuple[float, float]]) -> list[str]:
+    """What the host was doing in each of ``spans`` (``(s, t)`` pairs,
+    sorted and disjoint, as ``gaps`` gives them): the shortest host event
+    covering at least half of it, else the one covering most; of equals,
+    the first in ``events``. One sweep over the host events in order of
+    start: those that start before a span ends are taken in, and those
+    that end before it starts are let go, since they end before every
+    later span starts too."""
+    host = sorted(((e.start, i, e) for i, e in enumerate(events)
+                   if not is_device(e.plane) and e.name != WINDOW_SPAN),
+                  key=lambda x: (x[0], x[1]))
+    names, live, nxt = [], [], 0
+    for s, t in spans:
+        while nxt < len(host) and host[nxt][0] < t:
+            live.append(host[nxt][1:])
+            nxt += 1
+        live = sorted((ie for ie in live if ie[1].end > s),
+                      key=lambda ie: ie[0])
+        best, best_key = "no host event", None
+        for _, e in live:
+            c = _clip(e, s, t)
+            if c is None:
+                continue
+            cover = (c[1] - c[0]) / (t - s)
+            key = (cover >= 0.5, -e.dur if cover >= 0.5 else cover)
+            if best_key is None or key > best_key:
+                best, best_key = e.name, key
+        names.append(best)
+    return names
 
 
 def self_times(events: list[Ev]) -> list[tuple[Ev, float]]:
@@ -224,8 +239,9 @@ def summarize(events: list[Ev]) -> Summary:
         ops[e.short()] += own * 1e-9 / n_dev
     # idle gaps of the first device, named by the host's activity
     first = sorted(by_dev)[0]
-    named = [(host_activity(events, s, t), (t - s) * 1e-9)
-             for s, t in gaps(by_dev[first], lo, hi)]
+    idle = gaps(by_dev[first], lo, hi)
+    named = [(name, (t - s) * 1e-9)
+             for name, (s, t) in zip(host_activity(events, idle), idle)]
     named.sort(key=lambda kv: -kv[1])
     return Summary(window_s=(hi - lo) * 1e-9, busy_s=busy * 1e-9,
                    n_devices=n_dev, op_seconds=dict(ops), gap_list=named,

@@ -1,10 +1,22 @@
-"""The traffic generator, and the open loop that drives a service.
+"""The traffic generator, and the open and closed loops that drive a
+service.
 
 A traffic mix is a data file, ``chipbench/traffic/<mix>.json``, read by
 the functions here. Its keys:
 
 * ``rate_qps``: the offered rate of the open loop (independent users,
   Poisson arrivals on an absolute schedule);
+* or, for a closed loop (callers that each wait for their answer),
+  ``clients``, ``recall_per_client`` and ``requests_per_client``: each
+  client sends its next request as soon as its previous answer is
+  recorded (``ClosedLoop``); the first ``recall_per_client`` requests
+  of every client are the check set, which ``recall`` is taken over; and
+  the generator makes ``clients * requests_per_client`` requests, more
+  than any client can send in a run. A mix with ``clients`` runs closed;
+* ``data_seed`` (optional): the corpus and the index build come from
+  this seed in every run, not from the run's seed, so every run serves
+  one deployment; a mix's own module may draw its request sample from
+  it too, and order it by the run's seed;
 * ``ramp_s``: seconds of the same traffic before the window opens, so
   that the window starts at steady lane occupancy;
 * ``drain_s``: how long past the window's close an answer is waited for;
@@ -24,11 +36,12 @@ the gaps are one sample of independent exponential gaps (Poisson
 arrivals), the same for every seed, in an order the seed draws
 (``open_offsets``); the sigmas are the listed ones, each as often as the
 others, in an order the seed draws. The corpus and the query vectors
-are drawn afresh.
+are drawn afresh, but for what a mix pins with ``data_seed``.
 
 Open-loop requests are timed from their due time, which is absolute
 (``t0 + offset``): a sender that runs late does not move later requests,
-and its lateness is recorded for every request.
+and its lateness is recorded for every request. A closed-loop request is
+due when its client sends it.
 """
 
 from __future__ import annotations
@@ -37,6 +50,7 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
+import sys
 import threading
 import time
 import types
@@ -92,6 +106,8 @@ def load(path) -> dict:
 
 def request_count(mix: dict, seconds: float) -> int:
     """Requests the mix generates for a window of ``seconds``."""
+    if "clients" in mix:
+        return int(mix["clients"]) * int(mix["requests_per_client"])
     dur = float(mix["ramp_s"]) + float(seconds)
     return max(1, int(round(float(mix["rate_qps"]) * dur)))
 
@@ -218,6 +234,94 @@ class OpenLoop:
         self._stop.set()
         self._thread.join()
         self._pool.shutdown(wait=True)
+
+
+class ClosedLoop:
+    """``clients`` threads, each of which sends its next request as soon
+    as the answer (or error) to its previous one is recorded, with no
+    think time, so a client never has more than one request outstanding.
+    Client ``c`` walks requests ``c, c + clients, c + 2 * clients, ...``
+    of ``requests``, from ``t0`` on.
+
+    Sending stops at ``t_close``, except that a client that has not yet
+    sent its first ``keep`` requests (the check set: the first
+    ``clients * keep`` of ``requests``, the same whatever the speed) goes
+    on until it has. No client sends at or after ``t_end``; ``stop``
+    waits for the clients until then. A client that runs out of requests
+    stops, and ``stop`` raises: the mix has too few."""
+
+    def __init__(self, submit: Callable, requests: list[Request],
+                 clients: int, keep: int, t0: float, t_close: float,
+                 t_end: float, annotate):
+        self.submit, self.annotate = submit, annotate
+        self.requests, self.clients, self.keep = requests, clients, keep
+        self.t0, self.t_close, self.t_end = t0, t_close, t_end
+        self._sent: list[list[Record]] = [[] for _ in range(clients)]
+        self.ran_out: list[int] = []
+        self._threads = [threading.Thread(target=self._client, args=(c,),
+                                          name=f"chipbench-client-{c}",
+                                          daemon=True)
+                         for c in range(clients)]
+
+    def start(self) -> "ClosedLoop":
+        for t in self._threads:
+            t.start()
+        return self
+
+    def _client(self, c: int) -> None:
+        sent, i = self._sent[c], c
+        time.sleep(max(0.0, self.t0 - time.perf_counter()))
+        while True:
+            now = time.perf_counter()
+            if now >= self.t_end or (now >= self.t_close
+                                     and len(sent) >= self.keep):
+                return
+            if i >= len(self.requests):
+                self.ran_out.append(c)
+                return
+            rec = Record(req=self.requests[i], due=now)
+            sent.append(rec)
+            _sender(self.submit, rec, self.annotate)
+            if not rec.settled.wait(max(0.0, self.t_end
+                                        - time.perf_counter())):
+                return
+            i += self.clients
+
+    def stop(self) -> None:
+        """Wait for the clients until ``t_end``; record every request of
+        the check set that was never sent as failed, so that it counts as
+        missing. Raises RuntimeError where a client ran out of
+        requests."""
+        for t in self._threads:
+            t.join(max(0.0, self.t_end - time.perf_counter()) + 1.0)
+        for c, sent in enumerate(self._sent):
+            for i in range(c + len(sent) * self.clients,
+                           self.clients * self.keep, self.clients):
+                sent.append(Record(req=self.requests[i], due=self.t_end,
+                                   error="not sent by the drain's end"))
+        done = sorted(self.done_by(self.t_close))
+        print(f"closed loop: answers per client before the close: min "
+              f"{done[0]}, median {done[len(done) // 2]}, max {done[-1]}; "
+              f"check set {self.clients} x {self.keep}", file=sys.stderr,
+              flush=True)
+        if self.ran_out:
+            raise RuntimeError(
+                f"closed loop: clients {sorted(self.ran_out)} ran out of "
+                f"the mix's {len(self.requests)} requests; raise its "
+                f"requests_per_client")
+
+    @property
+    def records(self) -> list[Record]:
+        return [rec for sent in self._sent for rec in sent]
+
+    def check_set(self) -> list[Record]:
+        """The records of every client's first ``keep`` requests."""
+        return [rec for sent in self._sent for rec in sent[:self.keep]]
+
+    def done_by(self, t: float) -> list[int]:
+        """How many answers each client had received before ``t``."""
+        return [sum(1 for rec in sent if rec.t_done < t)
+                for sent in self._sent]
 
 
 def wait_all(records: list[Record], deadline: float) -> None:
